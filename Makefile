@@ -1,5 +1,5 @@
-# Tier-1 verification and benchmarks. Kernels run with interpret=True on
-# CPU (the Pallas TPU lowering is exercised on real hardware only).
+# Tier-1 verification and benchmarks. On CPU the Pallas kernels run in the
+# interpreter; `python chip_smoke.py` runs the served path on a TPU chip.
 
 PYTHON ?= python
 export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))

@@ -1,5 +1,5 @@
-"""Kernel-level benchmark: the batch-native Pallas kernels (interpret mode
-on CPU; the TPU lowering is the target) validated against ref.py and timed
+"""Kernel-level benchmark: the batch-native Pallas kernels (the Pallas
+interpreter off TPU, compiled on TPU) validated against ref.py and timed
 against the equivalent XLA path. On CPU interpret mode measures Python-level
 kernel semantics, so the numbers that matter here are the allclose/exact
 checks + the arithmetic-intensity report used in the §Perf kernel
@@ -41,7 +41,7 @@ def run() -> list[str]:
     kx, kp = jax.random.split(key)
     xf = jax.random.normal(kx, (b, n, d, rx))
     pf = jax.random.normal(kp, (n, l, k, d, rp))
-    got = cp_gram_pallas(xf, pf, epilogue="raw", block_l=2, interpret=True)
+    got = cp_gram_pallas(xf, pf, epilogue="raw", block_l=2)
     want = ref.cp_inner_ref(xf, pf.reshape(n, l * k, d, rp)).reshape(b, l, k)
     err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
     us_ref = time_fn(jax.jit(ref.cp_inner_ref), xf,
@@ -59,8 +59,7 @@ def run() -> list[str]:
     mults = make_mults(0, k)
     offs = jax.random.uniform(key, (l, k), minval=0.0, maxval=4.0)
     got_keys = cp_gram_pallas(xf, pf, offs, jnp.asarray(mults)[None],
-                              epilogue="e2lsh-keys", w=4.0, block_l=2,
-                              interpret=True)
+                              epilogue="e2lsh-keys", w=4.0, block_l=2)
     want_keys = _combine_codes(
         ref.e2lsh_quant_ref(got.reshape(b, l * k), offs.reshape(-1), 4.0)
         .reshape(b, l, k), mults)
@@ -75,7 +74,7 @@ def run() -> list[str]:
     b, n, d, r, l, k = 32, 4, 32, 16, 4, 8
     xc = jax.random.normal(kx, (b, n, r, d, r))
     pc = jax.random.normal(kp, (n, l, k, r, d, r))
-    got = tt_inner_pallas(xc, pc, epilogue="raw", block_l=2, interpret=True)
+    got = tt_inner_pallas(xc, pc, epilogue="raw", block_l=2)
     want = ref.tt_inner_ref(xc, pc.reshape(n, l * k, r, d, r)).reshape(b, l, k)
     err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
     us_ref = time_fn(jax.jit(ref.tt_inner_ref), xc,
@@ -87,7 +86,7 @@ def run() -> list[str]:
     rows.append(emit("kernels/tt_inner/intensity", us_ref,
                      f"{flops / bytes_:.2f}"))
     got_keys = tt_inner_pallas(xc, pc, None, jnp.asarray(make_mults(0, k))[None],
-                               epilogue="srp-keys", block_l=2, interpret=True)
+                               epilogue="srp-keys", block_l=2)
     want_keys = _combine_codes((got > 0).astype(jnp.int32), make_mults(0, k))
     n_bad = int(jnp.sum(got_keys != want_keys))
     rows.append(emit("kernels/tt_inner/fused_keys_exact", us_ref, f"{n_bad}"))
@@ -124,7 +123,7 @@ def run() -> list[str]:
 
     # SRP pack kernel
     v = jax.random.normal(key, (256, 256))
-    got = srp_pack_pallas(v, block_b=8, interpret=True)
+    got = srp_pack_pallas(v, block_b=8)
     want = ref.srp_pack_ref(v)
     n_bad = int(jnp.sum(got != want))
     us_ref = time_fn(jax.jit(ref.srp_pack_ref), v)

@@ -7,6 +7,9 @@ import sys
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (collision, durability, fused_probe,
                             hash_throughput, index_ingest,
                             index_multiprobe, index_mutation, index_qps,

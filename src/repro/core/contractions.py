@@ -22,9 +22,15 @@ import jax.numpy as jnp
 
 from repro.core.tensor_formats import CPTensor, TTTensor
 
+# Scores are exact float32 on every backend. On TPU an f32 matmul at the
+# default precision rounds its operands to bfloat16, which in the expanded
+# ||X||^2 + ||Y||^2 - 2<X,Y> distance leaves near neighbours with no correct
+# digit; HIGHEST keeps f32 there and changes nothing on CPU.
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 def inner_dense_dense(x: jax.Array, y: jax.Array) -> jax.Array:
-    return jnp.vdot(x, y)
+    return jnp.vdot(x, y, precision=_EXACT)
 
 
 def inner_cp_cp(x: CPTensor, y: CPTensor) -> jax.Array:
@@ -35,7 +41,7 @@ def inner_cp_cp(x: CPTensor, y: CPTensor) -> jax.Array:
     """
     h = None
     for fx, fy in zip(x.factors, y.factors):
-        g = fx.T @ fy  # (R^, R)
+        g = jnp.matmul(fx.T, fy, precision=_EXACT)  # (R^, R)
         h = g if h is None else h * g
     return (x.scale * y.scale) * jnp.sum(h)
 
@@ -48,7 +54,7 @@ def inner_tt_tt(x: TTTensor, y: TTTensor) -> jax.Array:
     """
     s = jnp.ones((1, 1), x.cores[0].dtype)
     for gx, gy in zip(x.cores, y.cores):
-        s = jnp.einsum("ab,aic,bid->cd", s, gx, gy)
+        s = jnp.einsum("ab,aic,bid->cd", s, gx, gy, precision=_EXACT)
     return (x.scale * y.scale) * s.reshape(())
 
 
@@ -63,7 +69,7 @@ def inner_cp_tt(x: CPTensor, y: TTTensor) -> jax.Array:
     s = jnp.ones((rank, 1), x.factors[0].dtype)
     for a, g in zip(x.factors, y.cores):
         # s: (R^, r_prev), g: (r_prev, d, r_next), a: (d, R^)
-        s = jnp.einsum("ra,aib,ir->rb", s, g, a)
+        s = jnp.einsum("ra,aib,ir->rb", s, g, a, precision=_EXACT)
     return (x.scale * y.scale) * jnp.sum(s)
 
 
@@ -73,20 +79,22 @@ def inner_dense_cp(x: jax.Array, y: CPTensor) -> jax.Array:
     Cost O(R d^N) and O(d^{N-1} R) intermediate memory — never materializes
     the d^N projection vector of the naive method.
     """
-    t = jnp.tensordot(y.factors[0], x, axes=(0, 0))  # (R, d2, ..., dN)
+    t = jnp.tensordot(y.factors[0], x, axes=(0, 0),
+                      precision=_EXACT)  # (R, d2, ..., dN)
     for f in y.factors[1:]:
         # t: (R, d_k, rest...), f: (d_k, R) -> diagonal in R
-        t = jnp.einsum("ri...,ir->r...", t, f)
+        t = jnp.einsum("ri...,ir->r...", t, f, precision=_EXACT)
     return y.scale * jnp.sum(t)
 
 
 def inner_dense_tt(x: jax.Array, y: TTTensor) -> jax.Array:
     """<X, Y> for dense X, TT Y: sweep cores left to right. Cost O(R^2 d^N)."""
     g0 = y.cores[0]  # (1, d1, r1)
-    t = jnp.tensordot(g0[0], x, axes=(0, 0))  # (r1, d2, ..., dN)
+    t = jnp.tensordot(g0[0], x, axes=(0, 0),
+                      precision=_EXACT)  # (r1, d2, ..., dN)
     for core in y.cores[1:]:
         # t: (r_prev, d_k, rest...), core: (r_prev, d_k, r_next)
-        t = jnp.einsum("ai...,air->r...", t, core)
+        t = jnp.einsum("ai...,air->r...", t, core, precision=_EXACT)
     return y.scale * t.reshape(())
 
 

@@ -32,7 +32,14 @@ case. Which backend evaluates the fused program is controlled by the
                    fall back to the XLA path. Codes are bit-identical
                    across backends (pinned by tests/test_hash_backends.py).
   * ``"auto"``   — the ``REPRO_HASH_BACKEND`` env var if set (read at
-                   trace time), else pallas on TPU and xla elsewhere.
+                   trace time), else xla on every platform. The TPU v5e
+                   compiler refuses the hash kernels (an (L, K) offsets
+                   block off the (8, 128) tiling, a ``scatter`` in the TT
+                   chain start, a uint32 reduction, and 4-D/5-D shape
+                   casts around the batched dot), so ``auto`` stays on xla
+                   until a kernel compiles and beats it in a benchmark
+                   cell. An explicit ``"pallas"`` still runs the kernel,
+                   and its compiler error reaches the caller.
 """
 
 from __future__ import annotations
@@ -137,13 +144,13 @@ class LSHFamily:
 
     def resolved_backend(self) -> str:
         """'xla' or 'pallas': the explicit knob, else the REPRO_HASH_BACKEND
-        env var (read at trace time), else pallas on TPU / xla elsewhere."""
+        env var (read at trace time), else xla on every platform (see the
+        module docstring for why not pallas on TPU)."""
         b = self.hash_backend
         if b == "auto":
             b = os.environ.get("REPRO_HASH_BACKEND", "").strip().lower() or "auto"
         if b == "auto":
-            from repro.kernels.ops import on_tpu
-            b = "pallas" if on_tpu() else "xla"
+            b = "xla"
         if b not in ("xla", "pallas"):
             raise ValueError(
                 f"hash_backend must be one of {HASH_BACKENDS}, got {b!r}")

@@ -78,21 +78,27 @@ PROBE_BACKENDS = ("auto", "xla", "pallas")
 
 def resolved_probe_backend(probe_backend: str = "auto") -> str:
     """'xla' or 'pallas': the explicit knob, else the REPRO_PROBE_BACKEND
-    env var (read at trace time), else pallas on TPU / xla elsewhere —
-    mirroring ``LSHFamily.resolved_backend`` for the hashing stage.
+    env var (read at trace time), else xla on every platform — mirroring
+    ``LSHFamily.resolved_backend`` for the hashing stage.
 
     'xla' is the restructured segment-major schedule (one fused scan over
     segments, hoisted-norm re-rank, packed top-k selection); 'pallas' the
-    fused query kernel in ``repro.kernels.fused_query`` (interpret mode on
-    CPU). Both are bit-identical to the reference planner
+    fused query kernel in ``repro.kernels.fused_query`` (interpret mode off
+    TPU). Both are bit-identical to the reference planner
     (``segmented_query_reference``), pinned by tests/test_fused_probe.py.
+
+    ``auto`` does not pick pallas on TPU: the v5e compiler refuses the
+    fused query kernel (a uint32 reduction in the key combine, then
+    ``searchsorted``/``argsort``/a two-operand ``lax.sort`` in the body),
+    and its whole-segment VMEM blocks exceed a core's VMEM at deployment
+    sizes. An explicit 'pallas' still runs the kernel and its compiler
+    error reaches the caller.
     """
     b = (probe_backend or "auto").strip().lower()
     if b == "auto":
         b = os.environ.get("REPRO_PROBE_BACKEND", "").strip().lower() or "auto"
     if b == "auto":
-        from repro.kernels.ops import on_tpu
-        b = "pallas" if on_tpu() else "xla"
+        b = "xla"
     if b not in ("xla", "pallas"):
         raise ValueError(
             f"probe_backend must be one of {PROBE_BACKENDS}, got "
@@ -774,6 +780,37 @@ def shard_topk_with_deltas(metric, topk, cap, delta_caps, queries, base_s,
 # ---------------------------------------------------------------------------
 
 
+# Items per step of the per-item self-inner sweep. On TPU the sweep
+# relayouts each item's cores so that a TT core's 12-wide mode dim pads to
+# 128 lanes (10.7x); over 1M TT items at once that is 17.2 GB of
+# temporaries, more than a v5e's HBM. Chunks of this many items keep it
+# near 1 GB.
+SELF_INNER_CHUNK = 1 << 16
+
+
+def self_inners(corpus, chunk: int = SELF_INNER_CHUNK) -> jax.Array:
+    """(m,) per-item <Y, Y>, ``chunk`` items at a time.
+
+    Each chunk runs the same identity-gather-into-nested-vmap structure as
+    one sweep over the whole segment (see ``hoisted_scores``), so the
+    values are bit-equal to it; the tail chunk re-reads the last item and
+    drops the extras."""
+    inner = contractions.inner
+    m = jax.tree.leaves(corpus)[0].shape[0]
+
+    def sweep(idx):
+        rows = tree_index(corpus, idx[None])              # leaves (1, c, ...)
+        return jax.vmap(
+            lambda ys: jax.vmap(lambda y: inner(y, y))(ys))(rows)[0]
+
+    if m <= chunk:
+        return sweep(jnp.arange(m))
+    starts = jnp.arange(-(-m // chunk)) * chunk
+    out = jax.lax.map(
+        lambda s: sweep(jnp.minimum(s + jnp.arange(chunk), m - 1)), starts)
+    return out.reshape(-1)[:m]
+
+
 def hoisted_scores(metric, queries, corpus, safe):
     """Exact re-rank scores of gathered candidates, hoisted-norm schedule.
 
@@ -796,13 +833,19 @@ def hoisted_scores(metric, queries, corpus, safe):
     row-vmap over the contiguous corpus can round the last bit differently
     from the reference's batched gathered dots on some shapes. Routing the
     hoisted sweep through the identical structure keeps the values
-    bit-equal at every shape, not just the benchmarked ones.
+    bit-equal at every shape, not just the benchmarked ones. Large
+    segments sweep in chunks (``self_inners``) to bound the temporaries.
     """
     inner = contractions.inner
-    m = jax.tree.leaves(corpus)[0].shape[0]
-    rows = tree_index(corpus, jnp.arange(m)[None])        # leaves (1, m, ...)
-    yy = jax.vmap(
-        lambda ys: jax.vmap(lambda y: inner(y, y))(ys))(rows)[0]  # (m,)
+    if (isinstance(corpus, jax.Array) and isinstance(queries, jax.Array)
+            and corpus.ndim > 2):
+        # dense items as flat rows (vdot flattens its operands anyway): a
+        # gathered (B, W, d1, d2) block whose minor dim is narrower than
+        # the TPU's 128 lanes is padded up to them — 8x for 8 x 16, which
+        # at B*W = 4M gathered rows exceeds a v5e's 16 GB
+        corpus = corpus.reshape(corpus.shape[0], -1)
+        queries = queries.reshape(queries.shape[0], -1)
+    yy = self_inners(corpus)                              # (m,)
     qq = jax.vmap(lambda q: inner(q, q))(queries)         # (B,)
     sub = tree_index(corpus, safe)                        # leaves (B, W, ...)
     qy = jax.vmap(

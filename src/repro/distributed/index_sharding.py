@@ -31,7 +31,6 @@ import functools
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed import sharding
@@ -124,11 +123,11 @@ def shard_map_query(family, base, deltas, mults, queries, *, metric, topk,
         return ids[None], scores[None], n_cand[None]
 
     sharded_spec, rep = P(axis), P()
-    per_shard = shard_map(
-        body, mesh,
+    per_shard = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(sharded_spec, sharded_spec, rep, rep),
         out_specs=(sharded_spec,) * 3,
-        check_rep=False,
+        check_vma=False,
     )(base, deltas, keys, queries)
     return segments.merge_topk(metric, topk, *per_shard)
 
@@ -154,10 +153,10 @@ def shard_map_query_reference(family, base, deltas, mults, queries, *, metric,
         return ids[None], scores[None], n_cand[None]
 
     sharded_spec, rep = P(axis), P()
-    per_shard = shard_map(
-        body, mesh,
+    per_shard = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(sharded_spec, sharded_spec, rep, rep),
         out_specs=(sharded_spec,) * 3,
-        check_rep=False,
+        check_vma=False,
     )(base, deltas, keys, queries)
     return segments.merge_topk(metric, topk, *per_shard)

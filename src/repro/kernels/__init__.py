@@ -12,10 +12,13 @@
                  the LSH families
   ref.py         pure-jnp oracles for allclose/bit-exact validation
 
-Kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling) and are
-validated on this CPU container with interpret=True.
+Kernels are written for TPU (pl.pallas_call + BlockSpec VMEM tiling) and
+are tested off TPU in the Pallas interpreter (``interpret=None`` resolves
+by platform). None of them compiles for a TPU v5e yet, so the LSH
+families' ``auto`` backends resolve to xla there.
 """
 
+from repro.kernels.epilogues import on_tpu
 from repro.kernels.ops import (cp_inner_products, tt_inner_products,
-                               srp_pack, e2lsh_quantize, fused_hash, on_tpu)
+                               srp_pack, e2lsh_quantize, fused_hash)
 from repro.kernels import ref

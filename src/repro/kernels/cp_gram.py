@@ -36,7 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.epilogues import apply_epilogue, out_struct
+from repro.kernels.epilogues import (apply_epilogue, default_interpret,
+                                     out_struct)
 
 
 def _cp_hash_kernel(x_ref, p_ref, b_ref, m_ref, o_ref, acc_ref, *,
@@ -71,7 +72,7 @@ def cp_gram_pallas(x_factors: jax.Array, p_factors: jax.Array,
                    mults: jax.Array | None = None, *,
                    epilogue: str = "raw", w: float = 1.0, scale: float = 1.0,
                    block_b: int = 8, block_l: int = 1,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
     """x_factors (B, N, d, Rx), p_factors (N, L, K, d, Rp) ->
     (B, L, K) values/codes, (B, L) keys or (B, L, K/32) packed words,
     per ``epilogue`` (see kernels/epilogues.py).
@@ -110,5 +111,5 @@ def cp_gram_pallas(x_factors: jax.Array, p_factors: jax.Array,
         out_shape=out,
         scratch_shapes=[pltpu.VMEM((block_b, rx, block_l * k, rp),
                                    jnp.float32)],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(x_factors, p_factors, offsets, mults)

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.epilogues import default_interpret
+
 
 def _e2lsh_quant_kernel(v_ref, b_ref, o_ref, *, inv_w: float):
     o_ref[...] = jnp.floor((v_ref[...] + b_ref[...]) * inv_w).astype(jnp.int32)
@@ -21,7 +23,8 @@ def _e2lsh_quant_kernel(v_ref, b_ref, o_ref, *, inv_w: float):
 
 @functools.partial(jax.jit, static_argnames=("w", "block_b", "interpret"))
 def e2lsh_quant_pallas(values: jax.Array, offsets: jax.Array, w: float,
-                       block_b: int = 8, interpret: bool = True) -> jax.Array:
+                       block_b: int = 8,
+                       interpret: bool | None = None) -> jax.Array:
     """values (B, K), offsets (K,), bucket width w -> int32 (B, K)."""
     b, k = values.shape
     assert b % block_b == 0, (b, block_b)
@@ -36,5 +39,5 @@ def e2lsh_quant_pallas(values: jax.Array, offsets: jax.Array, w: float,
         ],
         out_specs=pl.BlockSpec((block_b, k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, k), jnp.int32),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(values, offsets[None, :])

@@ -35,6 +35,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def default_interpret(interpret: bool | None) -> bool:
+    """Every kernel's ``interpret=None`` default: compiled for the chip on
+    TPU, the Pallas interpreter on any other backend."""
+    return (not on_tpu()) if interpret is None else interpret
+
+
 EPILOGUES = ("raw", "e2lsh", "srp", "e2lsh-keys", "srp-keys", "srp-packed")
 
 # Packed-selection sentinels: an invalid candidate slot carries the largest

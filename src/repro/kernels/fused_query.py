@@ -29,10 +29,13 @@ sign margins, and those are defined on the XLA projection path for every
 ``hash_backend`` (see ``hash_batch_aux``) — keeping that contract here
 keeps kernel keys bit-identical to the planner's under any hash backend.
 
-On this CPU container the kernel runs with interpret=True (the TPU
-lowering is the target; segment arrays as whole-array VMEM refs bound the
-corpus sizes a real TPU core can serve — the shard_map-vs-fused TPU
-measurement is deferred, see ROADMAP).
+The kernel does not compile for a TPU v5e yet: Mosaic refuses the uint32
+reduction of the key combine, and past it the body still calls
+``searchsorted``, ``argsort`` and a two-operand ``lax.sort``. Each
+segment's arrays are also passed as whole-array VMEM blocks, which at
+deployment sizes (1M x 128 f32 is 512 MB) exceed a core's VMEM. So
+``probe_backend='auto'`` resolves to xla on every platform, and off TPU
+this kernel runs in the Pallas interpreter.
 """
 
 from __future__ import annotations
@@ -48,7 +51,7 @@ from repro.core import probing
 from repro.core import projections as proj_lib
 from repro.core import segments as _seg
 from repro.kernels import epilogues as _epi
-from repro.kernels.ops import _default_interpret, _pad_axis
+from repro.kernels.ops import _pad_axis
 
 
 def _expand_probe_keys(aux, base, mults, *, e2, probes, pa, pb):
@@ -242,7 +245,7 @@ def fused_query(family, segs, mults, queries, *, metric, topk, caps,
         out_shape=(jax.ShapeDtypeStruct((b_pad, topk), jnp.int32),
                    jax.ShapeDtypeStruct((b_pad, topk), jnp.float32),
                    jax.ShapeDtypeStruct((b_pad, 1), jnp.int32)),
-        interpret=_default_interpret(interpret),
+        interpret=_epi.default_interpret(interpret),
     )(*inputs)
     return out_ids[:b], out_scores[:b], out_nc[:b, 0]
 

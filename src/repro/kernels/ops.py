@@ -16,8 +16,11 @@ batch of CP/TT inputs straight to integer codes, combined uint32 bucket
 keys, or packed SRP signatures (see kernels/epilogues.py) — the raw
 projection values never round-trip through HBM.
 
-On this CPU container kernels always run with interpret=True (the TPU
-lowering is the target; pass interpret=False on real hardware).
+Every wrapper and kernel takes ``interpret=None`` by default: compiled on
+TPU, the Pallas interpreter on any other backend. None of these kernels
+compiles for a TPU v5e yet (the LSH families' ``hash_backend='auto'``
+therefore resolves to xla; see ``repro.core.lsh``), so on TPU only an
+explicit ``pallas`` request reaches them.
 """
 
 from __future__ import annotations
@@ -29,18 +32,11 @@ from repro.core.projections import CPProjection, TTProjection
 from repro.core.tensor_formats import CPTensor, TTTensor
 from repro.kernels.cp_gram import cp_gram_pallas
 from repro.kernels.e2lsh_quant import e2lsh_quant_pallas
+from repro.kernels.epilogues import default_interpret
 from repro.kernels.srp_pack import srp_pack_pallas
 from repro.kernels.tt_inner import tt_inner_pallas
 
 _LANES = 128  # TPU VPU lane width (f32 tile is (8, 128))
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _default_interpret(interpret):
-    return (not on_tpu()) if interpret is None else interpret
 
 
 def _pad_axis(a: jax.Array, axis: int, mult: int, value=0.0) -> jax.Array:
@@ -192,7 +188,7 @@ def fused_hash(xs, p, *, epilogue: str, kind: str, num_tables: int,
         "keys": "e2lsh-keys" if e2 else "srp-keys",
         "packed": "srp-packed",
     }[epilogue]
-    interpret = _default_interpret(interpret)
+    interpret = default_interpret(interpret)
 
     b = jax.tree.leaves(xs)[0].shape[0]
     if isinstance(p, CPProjection) and isinstance(xs, CPTensor):
@@ -248,7 +244,7 @@ def cp_inner_products(x: CPTensor, p: CPProjection,
     xf = _pad_axis(_stack_cp_batch(xb), 0, 8)
     pf = _stack_cp_proj(p, 1)
     out = cp_gram_pallas(xf, pf, epilogue="raw",
-                         interpret=_default_interpret(interpret))
+                         interpret=default_interpret(interpret))
     return (x.scale * p.scale) * out[0, 0]
 
 
@@ -264,7 +260,7 @@ def tt_inner_products(x: TTTensor, p: TTProjection,
     xf = _pad_axis(_stack_tt_batch(xb, rx), 0, 8)
     pf = _stack_tt_proj(p, rp, 1)
     out = tt_inner_pallas(xf, pf, epilogue="raw",
-                          interpret=_default_interpret(interpret))
+                          interpret=default_interpret(interpret))
     return (x.scale * p.scale) * out[0, 0]
 
 
@@ -280,7 +276,7 @@ def srp_pack(values: jax.Array, block_b: int = 8,
     v = _pad_axis(values.astype(jnp.float32), 1, 32, value=-1.0)
     v = _pad_axis(v, 0, block_b, value=-1.0)
     out = srp_pack_pallas(v, block_b=block_b,
-                          interpret=_default_interpret(interpret))
+                          interpret=default_interpret(interpret))
     return out[:b]
 
 
@@ -297,5 +293,5 @@ def e2lsh_quantize(values: jax.Array, offsets: jax.Array, w: float,
     v = _pad_axis(v, 1, _LANES)
     offs = _pad_axis(offsets.astype(jnp.float32), 0, _LANES)
     out = e2lsh_quant_pallas(v, offs, float(w), block_b=block_b,
-                             interpret=_default_interpret(interpret))
+                             interpret=default_interpret(interpret))
     return out[:b, :k]

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.epilogues import default_interpret
+
 
 def _srp_pack_kernel(v_ref, o_ref):
     # v_ref: (BBLK, K); o_ref: (BBLK, K // 32)
@@ -30,7 +32,7 @@ def _srp_pack_kernel(v_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
 def srp_pack_pallas(values: jax.Array, block_b: int = 8,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """values (B, K) with K % 32 == 0, B % block_b == 0 -> uint32 (B, K/32).
 
     ops.py pads K to a multiple of 32 with -1.0 (sign bit 0) and B to a
@@ -45,5 +47,5 @@ def srp_pack_pallas(values: jax.Array, block_b: int = 8,
         in_specs=[pl.BlockSpec((block_b, k), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_b, k // 32), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, k // 32), jnp.uint32),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(values)

@@ -38,7 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.epilogues import apply_epilogue, out_struct
+from repro.kernels.epilogues import (apply_epilogue, default_interpret,
+                                     out_struct)
 
 
 def _tt_hash_kernel(x_ref, p_ref, b_ref, m_ref, o_ref, s_ref, *,
@@ -81,7 +82,7 @@ def tt_inner_pallas(x_cores: jax.Array, p_cores: jax.Array,
                     mults: jax.Array | None = None, *,
                     epilogue: str = "raw", w: float = 1.0, scale: float = 1.0,
                     block_b: int = 8, block_l: int = 1,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """x_cores (B, N, Rx, d, Rx), p_cores (N, L, K, Rp, d, Rp) ->
     (B, L, K) values/codes, (B, L) keys or (B, L, K/32) packed words, per
     ``epilogue`` (see kernels/epilogues.py).
@@ -121,5 +122,5 @@ def tt_inner_pallas(x_cores: jax.Array, p_cores: jax.Array,
         out_shape=out,
         scratch_shapes=[pltpu.VMEM((block_b, block_l * k, rx, rp),
                                    jnp.float32)],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(x_cores, p_cores, offsets, mults)

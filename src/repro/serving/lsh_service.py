@@ -6,7 +6,7 @@ with one of the paper's families; queries arrive in batches and run through
 the segment-store indexes of ``repro.core.index`` as one jit-compiled
 program — batch-native fused hashing (projection -> discretize -> bucket
 keys in one program; ``build_service(..., hash_backend=...)`` picks the
-XLA einsum path or the Pallas kernels, 'auto' = pallas on TPU), vmapped
+XLA einsum path or the Pallas kernels, 'auto' = xla), vmapped
 ``searchsorted`` bucket probes over every segment's sorted key tables,
 tombstone filtering, and exact in-format re-rank — never leaving the
 accelerator until the final top-k.

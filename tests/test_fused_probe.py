@@ -28,6 +28,7 @@ a regression when absent.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -35,7 +36,8 @@ import numpy as np
 import pytest
 
 import grids
-from repro.core import DeviceLSHIndex, ShardedLSHIndex
+from repro.core import (DeviceLSHIndex, ShardedLSHIndex, cp_random_data,
+                        tt_random_data)
 from repro.core import segments as seg
 from repro.distributed import index_sharding
 from repro.serving.lsh_service import build_service
@@ -143,10 +145,30 @@ def test_sharded_bit_identity(kind, metric, shards, backend):
                 got, ref)
 
 
+@pytest.mark.parametrize("fmt", ["dense", "cp", "tt"])
+def test_self_inners_chunked_bitwise(fmt):
+    """The chunked per-item self-inner sweep (large segments) returns the
+    one-sweep values bit for bit, the short tail chunk included."""
+    key = jax.random.PRNGKey(3)
+    if fmt == "dense":
+        corpus = jax.random.normal(key, (N,) + grids.DIMS)
+    else:
+        gen = cp_random_data if fmt == "cp" else tt_random_data
+        corpus = jax.vmap(lambda k: gen(k, grids.DIMS, 2))(
+            jax.random.split(key, N))
+    whole = jax.jit(seg.self_inners)(corpus)
+    assert whole.shape == (N,)
+    for chunk in (7, 16, N):
+        got = jax.jit(functools.partial(seg.self_inners, chunk=chunk))(
+            corpus)
+        np.testing.assert_array_equal(
+            np.asarray(got).view(np.int32), np.asarray(whole).view(np.int32),
+            err_msg=f"chunk={chunk}")
+
+
 def test_resolved_probe_backend(monkeypatch):
     monkeypatch.delenv("REPRO_PROBE_BACKEND", raising=False)
-    assert seg.resolved_probe_backend("auto") == (
-        "pallas" if jax.default_backend() == "tpu" else "xla")
+    assert seg.resolved_probe_backend("auto") == "xla"
     # explicit knob wins over everything
     monkeypatch.setenv("REPRO_PROBE_BACKEND", "pallas")
     assert seg.resolved_probe_backend("xla") == "xla"
@@ -160,12 +182,10 @@ def test_probe_backend_threading():
     """The knob flows index -> service and is reported by probe_path."""
     fam = grids.grid_family("cp-e2lsh")
     corpus, queries = grids.corpus_and_queries(N, B)
-    on_cpu = jax.default_backend() != "tpu"
     idx = DeviceLSHIndex(fam, metric="euclidean", bucket_cap=4,
                          probe_backend="pallas").build(corpus)
     assert idx.probe_path == "pallas"
-    if on_cpu:
-        assert DeviceLSHIndex(fam, metric="euclidean").probe_path == "xla"
+    assert DeviceLSHIndex(fam, metric="euclidean").probe_path == "xla"
     svc = build_service(jax.random.PRNGKey(0), "cp-e2lsh", grids.DIMS,
                         corpus, num_codes=3, num_tables=4, rank=2,
                         bucket_width=6.0, bucket_cap=4,
