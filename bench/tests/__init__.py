@@ -1,0 +1,8 @@
+"""CPU tests of the benchmark harness, at a few thousand items."""
+
+import sys
+from pathlib import Path
+
+_SRC = str(Path(__file__).resolve().parents[2] / "src")
+if _SRC not in sys.path:
+    sys.path.insert(0, _SRC)
