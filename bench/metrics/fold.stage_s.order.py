@@ -1,0 +1,9 @@
+"""Seconds per compaction fold in its ``order`` phase: the self time of the
+``lsh.fold.order`` spans inside ``lsh.fold`` spans, over the traced
+window's folds (``bench/spans.py``)."""
+
+from bench import spans
+
+
+def read(ctx):
+    return spans.fold_stage_s(ctx, "order")

@@ -27,10 +27,18 @@ def compile_cache_dir() -> str:
 def enable_compile_cache() -> str:
     """Turn the persistent compilation cache on; returns its directory.
 
-    Call before the first compile. Sets nothing when the environment
+    Call before the first compile. Sets no directory when the environment
     variable is set, since JAX has already taken the directory from it.
+
+    The cache key includes the programs' metadata. By default JAX strips
+    it from the key, and an entry then hands back the executable of
+    whichever program with the same operations was compiled first, with
+    that program's op names: a program whose ``jax.named_scope`` stages
+    (``repro.core.segments.QUERY_STAGES``) changed, or were added, would
+    show the old names, or none, in a profiler trace.
     """
     path = compile_cache_dir()
     if not os.environ.get(_ENV):
         jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path

@@ -62,13 +62,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import probing, segments
 from repro.core.lsh import LSHFamily
 from repro.core.probing import QUERY_MODES
@@ -232,14 +232,15 @@ class _SegmentedIndex(_LSHIndexBase):
     def _maybe_auto_compact(self) -> None:
         """Compact when the delta count exceeds ``max_deltas``, accounting
         the fold's wall time separately (``auto_compact_s`` /
-        ``auto_compactions``) so callers timing an ``insert`` can split the
-        mutation cost from the compaction cost it occasionally triggers."""
+        ``auto_compactions``, fed from the ``lsh.fold`` span) so callers
+        timing an ``insert`` can split the mutation cost from the
+        compaction cost it occasionally triggers."""
         if len(self.store.deltas) <= self.max_deltas:
             return
-        t0 = time.perf_counter()
-        self.compact()
-        jax.block_until_ready(self.store.base.sorted_keys)
-        self.auto_compact_s += time.perf_counter() - t0
+        with tracing.span("lsh.fold", deltas=len(self.store.deltas)) as fold:
+            self.compact()
+            jax.block_until_ready(self.store.base.sorted_keys)
+        self.auto_compact_s += fold.seconds
         self.auto_compactions += 1
 
     def _reset_mutation_state(self) -> None:
@@ -374,24 +375,28 @@ class DeviceLSHIndex(_SegmentedIndex):
         self._reset_mutation_state()
         return self
 
-    def _new_store(self, keys, corpus,
-                   sort_throttled: bool = False) -> SegmentStore:
+    def _new_store(self, keys, corpus) -> SegmentStore:
         return SegmentStore(
             build_segment(keys, corpus, bucket_cap=self.bucket_cap,
-                          warn_layout=type(self).__name__,
-                          sort_throttled=sort_throttled),
+                          warn_layout=type(self).__name__),
             live_window=self.bucket_cap is not None)
 
     def _build_compact_store(self, store: SegmentStore) -> SegmentStore:
         # chunked assembly (the default) keeps every fold program bounded
         # so concurrently dispatched queries interleave with the build —
-        # values are bit-identical to the one-program gather
+        # values are bit-identical to the one-program gather. Its phases
+        # are spans: lsh.fold.order and .gather (effective_arrays_chunked),
+        # .sort (the throttled sort), .tables (the new store's lookups)
         if self.swap_chunk_rows is None:
             keys, corpus = store.effective_arrays()
             return self._new_store(keys, corpus)
         keys, corpus = store.effective_arrays_chunked(
             int(self.swap_chunk_rows))
-        return self._new_store(keys, corpus, sort_throttled=True)
+        seg = build_segment(keys, corpus, bucket_cap=self.bucket_cap,
+                            warn_layout=type(self).__name__,
+                            sort_throttled=True)
+        with tracing.span("lsh.fold.tables"):
+            return SegmentStore(seg, live_window=self.bucket_cap is not None)
 
     # -- query --------------------------------------------------------------
 
@@ -633,27 +638,30 @@ class ShardedLSHIndex(_SegmentedIndex):
     def _build_compact_store(self, store: SegmentStore) -> SegmentStore:
         """The shard-local fold, pure with respect to ``self``: builds and
         returns the replacement store; the live store (and every query
-        pinned to its view) is untouched."""
+        pinned to its view) is untouched. The chunked fold's phases are
+        the same ``lsh.fold.*`` spans as the device index's."""
         s = store.base.shards
         segs = store._segments()
-        live2d = np.concatenate(
-            [store.live_host[off:off + g.slots].reshape(s, g.shard_size)
-             for off, g in zip(np.cumsum([0] + [g.slots for g in segs[:-1]]),
-                               segs)], axis=1)
-        pos2d = np.concatenate(
-            [p.reshape(s, g.shard_size)
-             for p, g in zip(store.slot_pos, segs)], axis=1)
-        counts = live2d.sum(axis=1).astype(np.int64)
-        new_ns = max(int(counts.max()), 1)
-        w = live2d.shape[1]
-        idx = np.full((s, new_ns), w, np.int64)
-        new_pos = np.full((s, new_ns), -1, np.int64)
-        eff_seq = np.cumsum(store._live_seq) - 1
-        for sh in range(s):
-            sel = np.flatnonzero(live2d[sh])    # slot order = seq order
-            idx[sh, :sel.size] = sel
-            new_pos[sh, :sel.size] = eff_seq[pos2d[sh, sel]]
-        keys_cat = jnp.concatenate([g.keys for g in segs], axis=1)
+        with tracing.span("lsh.fold.order"):
+            live2d = np.concatenate(
+                [store.live_host[off:off + g.slots].reshape(s, g.shard_size)
+                 for off, g in zip(
+                     np.cumsum([0] + [g.slots for g in segs[:-1]]), segs)],
+                axis=1)
+            pos2d = np.concatenate(
+                [p.reshape(s, g.shard_size)
+                 for p, g in zip(store.slot_pos, segs)], axis=1)
+            counts = live2d.sum(axis=1).astype(np.int64)
+            new_ns = max(int(counts.max()), 1)
+            w = live2d.shape[1]
+            idx = np.full((s, new_ns), w, np.int64)
+            new_pos = np.full((s, new_ns), -1, np.int64)
+            eff_seq = np.cumsum(store._live_seq) - 1
+            for sh in range(s):
+                sel = np.flatnonzero(live2d[sh])    # slot order = seq order
+                idx[sh, :sel.size] = sel
+                new_pos[sh, :sel.size] = eff_seq[pos2d[sh, sel]]
+            keys_cat = jnp.concatenate([g.keys for g in segs], axis=1)
         if self.swap_chunk_rows is None:
             corpus_cat = jax.tree.map(
                 lambda *xs: jnp.concatenate(xs, axis=1),
@@ -669,21 +677,23 @@ class ShardedLSHIndex(_SegmentedIndex):
             # (shard * slot) row space — with blocking between them, so
             # concurrent queries interleave with the build instead of
             # queueing behind one store-sized program
-            keys_n = segments._slab_gather_keys(
-                keys_cat, jnp.asarray(idx, jnp.int32))
-            jax.block_until_ready(keys_n)
-            segments._yield_slot()
+            with tracing.span("lsh.fold.order"):
+                keys_n = segments._slab_gather_keys(
+                    keys_cat, jnp.asarray(idx, jnp.int32))
+                jax.block_until_ready(keys_n)
+                segments._yield_slot()
             counts_j = jnp.asarray(counts, jnp.int32)
             tables = []
-            for table in range(keys_n.shape[-1]):
-                out = segments._sort_shard_table(
-                    keys_n[:, :, table], counts_j, shard_size=new_ns)
-                jax.block_until_ready(out)
-                segments._yield_slot()
-                tables.append(out)
-            perm = jnp.stack([t[0] for t in tables], axis=1)
-            sorted_keys = jnp.stack([t[1] for t in tables], axis=1)
-            max_runs = jnp.stack([t[2] for t in tables])
+            with tracing.span("lsh.fold.sort"):
+                for table in range(keys_n.shape[-1]):
+                    out = segments._sort_shard_table(
+                        keys_n[:, :, table], counts_j, shard_size=new_ns)
+                    jax.block_until_ready(out)
+                    segments._yield_slot()
+                    tables.append(out)
+                perm = jnp.stack([t[0] for t in tables], axis=1)
+                sorted_keys = jnp.stack([t[1] for t in tables], axis=1)
+                max_runs = jnp.stack([t[2] for t in tables])
             valid = idx < w          # sentinel w marks pad rows
             sh_i, col_i = np.nonzero(valid)
             srcs, src_idxs, dst_idxs = [], [], []
@@ -711,11 +721,12 @@ class ShardedLSHIndex(_SegmentedIndex):
         seg = segments.ShardedSegment(
             keys=keys_n, sorted_keys=sorted_keys, perm=perm, corpus=corpus_n,
             cap=cap, counts=tuple(int(c) for c in counts))
-        if self.mesh is not None:
-            seg = self._place_segment(seg, shadow=True)
-        return SegmentStore(
-            seg, place=self._place(), base_pos=new_pos.reshape(-1),
-            live_window=self.bucket_cap is not None)
+        with tracing.span("lsh.fold.tables"):
+            if self.mesh is not None:
+                seg = self._place_segment(seg, shadow=True)
+            return SegmentStore(
+                seg, place=self._place(), base_pos=new_pos.reshape(-1),
+                live_window=self.bucket_cap is not None)
 
     def _pre_publish(self, pending: PendingSwap) -> None:
         # The shard layout changes under the flip: a shard-local compact

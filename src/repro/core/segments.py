@@ -60,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import contractions, probing
 # The universal bucket hash lives with the families (lsh.hash_keys fuses it
 # into the hashing program); re-exported here for the host/table builders.
@@ -151,10 +152,11 @@ def query_keys(family, mults, queries, probes: int = 1) -> jax.Array:
     candidate bucket keys -> (L, T, B); slot 0 along T is the base key,
     bit-identical to the single-probe tensor.
     """
-    if probes == 1:
-        return family.hash_keys(queries, jnp.asarray(mults)).T
-    keys = probing.probe_keys(family, mults, queries, probes=probes)
-    return jnp.moveaxis(keys, 0, -1)                      # (B,L,T) -> (L,T,B)
+    with jax.named_scope("hash"):
+        if probes == 1:
+            return family.hash_keys(queries, jnp.asarray(mults)).T
+        keys = probing.probe_keys(family, mults, queries, probes=probes)
+        return jnp.moveaxis(keys, 0, -1)                  # (B,L,T) -> (L,T,B)
 
 
 def _max_run_length(sorted_keys: jax.Array) -> jax.Array:
@@ -539,23 +541,26 @@ def _yield_slot() -> None:
     unless inside :func:`cooperative_build`, or when its ``busy``
     predicate says no foreground work is waiting)."""
     if _BUILD_YIELD_S > 0.0 and (_BUILD_BUSY_FN is None or _BUILD_BUSY_FN()):
-        time.sleep(_BUILD_YIELD_S)
+        with tracing.span("lsh.yield"):
+            time.sleep(_BUILD_YIELD_S)
 
 
 def _sort_tables_throttled(keys_t: jax.Array):
     """``_sort_tables`` issued as one bounded program per table, blocking
     between programs — identical values (tables sort independently). The
     chunked shadow build uses it so the fold's sort never queues one
-    all-tables program ahead of a concurrently dispatched query."""
+    all-tables program ahead of a concurrently dispatched query (span
+    ``lsh.fold.sort``)."""
     outs = []
-    for table in range(keys_t.shape[-2]):
-        out = _sort_tables(keys_t[..., table:table + 1, :])
-        jax.block_until_ready(out)
-        _yield_slot()
-        outs.append(out)
-    perm = jnp.concatenate([o[0] for o in outs], axis=-2)
-    sorted_keys = jnp.concatenate([o[1] for o in outs], axis=-2)
-    return perm, sorted_keys, jnp.max(jnp.stack([o[2] for o in outs]))
+    with tracing.span("lsh.fold.sort"):
+        for table in range(keys_t.shape[-2]):
+            out = _sort_tables(keys_t[..., table:table + 1, :])
+            jax.block_until_ready(out)
+            _yield_slot()
+            outs.append(out)
+        perm = jnp.concatenate([o[0] for o in outs], axis=-2)
+        sorted_keys = jnp.concatenate([o[1] for o in outs], axis=-2)
+        return perm, sorted_keys, jnp.max(jnp.stack([o[2] for o in outs]))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -592,27 +597,38 @@ def gather_rows_chunked(template, srcs, src_idxs, dst_idxs, out_rows, *,
     ``srcs`` are per-segment corpus pytrees with a flat leading axis;
     ``src_idxs``/``dst_idxs`` the matching host-side row maps into them
     and into the flat output. ``template`` supplies output leaf shapes.
+    The whole copy is one ``lsh.fold.gather`` span.
     """
-    buf = jax.tree.map(
-        lambda a: jnp.zeros((out_rows,) + a.shape[1:], a.dtype), template)
-    for src, s_idx, d_idx in zip(srcs, src_idxs, dst_idxs):
-        for c0 in range(0, len(s_idx), chunk):
-            s_c = np.asarray(s_idx[c0:c0 + chunk], np.int32)
-            d_c = np.asarray(d_idx[c0:c0 + chunk], np.int32)
-            if s_c.size < chunk:    # pad to the compiled chunk shape;
-                fill = chunk - s_c.size  # dst sentinel rows are dropped
-                s_c = np.pad(s_c, (0, fill))
-                d_c = np.pad(d_c, (0, fill), constant_values=out_rows)
-            buf = _scatter_rows_chunk(buf, src, jnp.asarray(s_c),
-                                      jnp.asarray(d_c))
-            jax.block_until_ready(jax.tree.leaves(buf))
-            _yield_slot()
-    return buf
+    with tracing.span("lsh.fold.gather", rows=int(out_rows)):
+        buf = jax.tree.map(
+            lambda a: jnp.zeros((out_rows,) + a.shape[1:], a.dtype), template)
+        for src, s_idx, d_idx in zip(srcs, src_idxs, dst_idxs):
+            for c0 in range(0, len(s_idx), chunk):
+                s_c = np.asarray(s_idx[c0:c0 + chunk], np.int32)
+                d_c = np.asarray(d_idx[c0:c0 + chunk], np.int32)
+                if s_c.size < chunk:    # pad to the compiled chunk shape;
+                    fill = chunk - s_c.size  # dst sentinel rows are dropped
+                    s_c = np.pad(s_c, (0, fill))
+                    d_c = np.pad(d_c, (0, fill), constant_values=out_rows)
+                buf = _scatter_rows_chunk(buf, src, jnp.asarray(s_c),
+                                          jnp.asarray(d_c))
+                jax.block_until_ready(jax.tree.leaves(buf))
+                _yield_slot()
+        return buf
 
 
 # ---------------------------------------------------------------------------
 # Probe / rank / merge — the shared query math
 # ---------------------------------------------------------------------------
+
+
+# The query program's stages, each a ``jax.named_scope`` in the functions
+# below and in ``query_keys``, so every program built from them
+# (``segmented_query``, ``sharded_query_vmap``, the ``shard_map`` body)
+# names its HLO ops by stage in their ``op_name`` metadata, which a
+# profiler trace carries. Scopes exist only at trace time: they change no
+# value and no runtime work.
+QUERY_STAGES = ("hash", "probe", "norms", "rerank", "select")
 
 
 def _probe_windows(sorted_keys, perm, keys, cap, live, win=None):
@@ -657,9 +673,10 @@ def probe_tables(sorted_keys, perm, keys, cap, live, win=None):
     base key), so ``n_cand`` counts distinct members at any T.
     """
     m = sorted_keys.shape[1]
-    ids, hit = _probe_windows(sorted_keys, perm, keys, cap, live, win)
-    cand, valid = _epi.dedup_windows(ids, hit, m)
-    return jnp.where(valid, cand, -1).astype(jnp.int32), valid
+    with jax.named_scope("probe"):
+        ids, hit = _probe_windows(sorted_keys, perm, keys, cap, live, win)
+        cand, valid = _epi.dedup_windows(ids, hit, m)
+        return jnp.where(valid, cand, -1).astype(jnp.int32), valid
 
 
 def select_topk(metric, topk, cand, scores, valid):
@@ -677,19 +694,20 @@ def select_topk(metric, topk, cand, scores, valid):
     what keeps selection independent of how items are laid out — the
     invariant behind mutated-vs-fresh parity for any shard routing.
     """
-    order_key = scores if metric == "euclidean" else -scores
-    _, _, s_cand, s_scores, s_valid = jax.lax.sort(
-        (~valid, order_key, cand, scores, valid),
-        dimension=1, is_stable=True, num_keys=3)
-    k = min(topk, cand.shape[1])
-    bad = _bad_score(metric)
-    ids = jnp.where(s_valid[:, :k], s_cand[:, :k], -1)
-    out_scores = jnp.where(s_valid[:, :k], s_scores[:, :k], bad)
-    if k < topk:
-        ids = jnp.pad(ids, ((0, 0), (0, topk - k)), constant_values=-1)
-        out_scores = jnp.pad(out_scores, ((0, 0), (0, topk - k)),
-                             constant_values=bad)
-    return ids, out_scores
+    with jax.named_scope("select"):
+        order_key = scores if metric == "euclidean" else -scores
+        _, _, s_cand, s_scores, s_valid = jax.lax.sort(
+            (~valid, order_key, cand, scores, valid),
+            dimension=1, is_stable=True, num_keys=3)
+        k = min(topk, cand.shape[1])
+        bad = _bad_score(metric)
+        ids = jnp.where(s_valid[:, :k], s_cand[:, :k], -1)
+        out_scores = jnp.where(s_valid[:, :k], s_scores[:, :k], bad)
+        if k < topk:
+            ids = jnp.pad(ids, ((0, 0), (0, topk - k)), constant_values=-1)
+            out_scores = jnp.pad(out_scores, ((0, 0), (0, topk - k)),
+                                 constant_values=bad)
+        return ids, out_scores
 
 
 def rank_candidates(metric, topk, queries, corpus, cand, valid):
@@ -803,12 +821,14 @@ def self_inners(corpus, chunk: int = SELF_INNER_CHUNK) -> jax.Array:
         return jax.vmap(
             lambda ys: jax.vmap(lambda y: inner(y, y))(ys))(rows)[0]
 
-    if m <= chunk:
-        return sweep(jnp.arange(m))
-    starts = jnp.arange(-(-m // chunk)) * chunk
-    out = jax.lax.map(
-        lambda s: sweep(jnp.minimum(s + jnp.arange(chunk), m - 1)), starts)
-    return out.reshape(-1)[:m]
+    with jax.named_scope("norms"):
+        if m <= chunk:
+            return sweep(jnp.arange(m))
+        starts = jnp.arange(-(-m // chunk)) * chunk
+        out = jax.lax.map(
+            lambda s: sweep(jnp.minimum(s + jnp.arange(chunk), m - 1)),
+            starts)
+        return out.reshape(-1)[:m]
 
 
 def hoisted_scores(metric, queries, corpus, safe):
@@ -842,20 +862,24 @@ def hoisted_scores(metric, queries, corpus, safe):
         # dense items as flat rows (vdot flattens its operands anyway): a
         # gathered (B, W, d1, d2) block whose minor dim is narrower than
         # the TPU's 128 lanes is padded up to them — 8x for 8 x 16, which
-        # at B*W = 4M gathered rows exceeds a v5e's 16 GB
-        corpus = corpus.reshape(corpus.shape[0], -1)
-        queries = queries.reshape(queries.shape[0], -1)
+        # at B*W = 4M gathered rows exceeds a v5e's 16 GB. The flat rows
+        # exist for the re-rank's gather, so a relayout copy they cost is
+        # counted there
+        with jax.named_scope("rerank"):
+            corpus = corpus.reshape(corpus.shape[0], -1)
+            queries = queries.reshape(queries.shape[0], -1)
     yy = self_inners(corpus)                              # (m,)
-    qq = jax.vmap(lambda q: inner(q, q))(queries)         # (B,)
-    sub = tree_index(corpus, safe)                        # leaves (B, W, ...)
-    qy = jax.vmap(
-        lambda q, ys: jax.vmap(lambda y: inner(q, y))(ys))(queries, sub)
-    if metric == "euclidean":
-        d2 = qq[:, None] + yy[safe] - 2.0 * qy
-        return jnp.sqrt(jnp.maximum(d2, 0.0))
-    nq = jnp.sqrt(jnp.maximum(qq, 0.0))
-    ny = jnp.sqrt(jnp.maximum(yy, 0.0))
-    return qy / (nq[:, None] * ny[safe])
+    with jax.named_scope("rerank"):
+        qq = jax.vmap(lambda q: inner(q, q))(queries)     # (B,)
+        sub = tree_index(corpus, safe)                    # leaves (B, W, ...)
+        qy = jax.vmap(
+            lambda q, ys: jax.vmap(lambda y: inner(q, y))(ys))(queries, sub)
+        if metric == "euclidean":
+            d2 = qq[:, None] + yy[safe] - 2.0 * qy
+            return jnp.sqrt(jnp.maximum(d2, 0.0))
+        nq = jnp.sqrt(jnp.maximum(qq, 0.0))
+        ny = jnp.sqrt(jnp.maximum(yy, 0.0))
+        return qy / (nq[:, None] * ny[safe])
 
 
 def segment_packed_candidates(metric, cap, queries, seg_arrays, keys):
@@ -865,12 +889,15 @@ def segment_packed_candidates(metric, cap, queries, seg_arrays, keys):
     shared implementations in ``repro.kernels.epilogues``."""
     corpus, sorted_keys, perm, live, eff, win = seg_arrays
     m = sorted_keys.shape[1]
-    ids, hit = _epi.probe_windows(sorted_keys, perm, keys, cap, live, win)
-    cand, valid = _epi.dedup_windows(ids, hit, m)
-    safe = jnp.where(valid, cand, 0)
+    with jax.named_scope("probe"):
+        ids, hit = _epi.probe_windows(sorted_keys, perm, keys, cap, live, win)
+        cand, valid = _epi.dedup_windows(ids, hit, m)
+        safe = jnp.where(valid, cand, 0)
+        n_cand = valid.sum(axis=1, dtype=jnp.int32)
     scores = hoisted_scores(metric, queries, corpus, safe)
-    hi, lo = _epi.pack_candidates(metric, eff[safe], scores, valid)
-    return hi, lo, valid.sum(axis=1, dtype=jnp.int32)
+    with jax.named_scope("select"):
+        hi, lo = _epi.pack_candidates(metric, eff[safe], scores, valid)
+    return hi, lo, n_cand
 
 
 def _packed_query_segments(metric, topk, queries, segs, caps, keys):
@@ -882,13 +909,14 @@ def _packed_query_segments(metric, topk, queries, segs, caps, keys):
     the same top-k in the same order."""
     parts = [segment_packed_candidates(metric, cap, queries, sa, keys)
              for sa, cap in zip(segs, caps)]
-    ids, scores = _epi.packed_select(
-        metric, topk,
-        jnp.concatenate([p[0] for p in parts], axis=1),
-        jnp.concatenate([p[1] for p in parts], axis=1))
-    n_cand = parts[0][2]
-    for _, _, nc in parts[1:]:
-        n_cand = n_cand + nc
+    with jax.named_scope("select"):
+        ids, scores = _epi.packed_select(
+            metric, topk,
+            jnp.concatenate([p[0] for p in parts], axis=1),
+            jnp.concatenate([p[1] for p in parts], axis=1))
+        n_cand = parts[0][2]
+        for _, _, nc in parts[1:]:
+            n_cand = n_cand + nc
     return ids, scores, n_cand
 
 
@@ -981,18 +1009,20 @@ def sharded_query_vmap(family, base, deltas, mults, queries, *, metric, topk,
         caps = (cap,) + tuple(delta_caps)
         parts = [segment_packed_candidates(metric, c, queries, sa, keys)
                  for sa, c in zip(segs, caps)]
-        nc = parts[0][2]
-        for _, _, n in parts[1:]:
-            nc = nc + n
-        return (jnp.concatenate([p[0] for p in parts], axis=1),
-                jnp.concatenate([p[1] for p in parts], axis=1), nc)
+        with jax.named_scope("select"):
+            nc = parts[0][2]
+            for _, _, n in parts[1:]:
+                nc = nc + n
+            return (jnp.concatenate([p[0] for p in parts], axis=1),
+                    jnp.concatenate([p[1] for p in parts], axis=1), nc)
 
     hi, lo, nc = jax.vmap(shard_packed, in_axes=(0, 0))(base, deltas)
     s, b, w = hi.shape
-    ids, scores = _epi.packed_select(metric, topk,
-                                     hi.transpose(1, 0, 2).reshape(b, s * w),
-                                     lo.transpose(1, 0, 2).reshape(b, s * w))
-    return ids, scores, nc.sum(axis=0)
+    with jax.named_scope("select"):
+        ids, scores = _epi.packed_select(
+            metric, topk, hi.transpose(1, 0, 2).reshape(b, s * w),
+            lo.transpose(1, 0, 2).reshape(b, s * w))
+        return ids, scores, nc.sum(axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "topk", "cap",
@@ -1323,7 +1353,9 @@ class SegmentStore:
     Every mutation ends by publishing a fresh immutable ``StoreView`` (one
     atomic attribute write); queries read ``store.view`` once and serve the
     whole program from it, so mutations racing a query from another thread
-    can never tear the segment/lookup pairing mid-read.
+    can never tear the segment/lookup pairing mid-read. A mutation's lookup
+    rebuild is one ``lsh.store.refresh`` span (``op=append|delete``); a
+    fold's new store is timed by its ``lsh.fold.tables`` span instead.
     """
 
     def __init__(self, base, *, place: Callable | None = None,
@@ -1538,9 +1570,10 @@ class SegmentStore:
         self.seq_len += n_new
         self.n_live += n_new
         eff = np.where(valid, start + (positions - seq0), 0)
-        lut = self._seg_luts(seg, valid, eff)
-        self._luts.append(lut)
-        self._wins.append(self._seg_win(seg, lut[0]))
+        with tracing.span("lsh.store.refresh", op="append"):
+            lut = self._seg_luts(seg, valid, eff)
+            self._luts.append(lut)
+            self._wins.append(self._seg_win(seg, lut[0]))
         self._publish()
 
     def delete_effective(self, ids: np.ndarray) -> int:
@@ -1559,7 +1592,8 @@ class SegmentStore:
         bounds = np.cumsum([seg.slots for seg in self._segments()])
         touched = set(np.searchsorted(bounds, slots,
                                       side="right").tolist())
-        self._refresh(touched)
+        with tracing.span("lsh.store.refresh", op="delete"):
+            self._refresh(touched)
         return int(ids.size)
 
     # -- effective (live) views --------------------------------------------
@@ -1599,26 +1633,28 @@ class SegmentStore:
         store-sized concatenate + gather. Bit-identical output; the
         shadow-build (``prepare_compact``) path uses it so concurrent
         queries never queue behind a store-sized program. Keys stay on the
-        one-program path — they are a few bytes per item."""
-        idx = self._live_slots_seq_order()
-        flat_keys = []
-        srcs, src_idxs, dst_idxs = [], [], []
-        off = 0
-        for seg in self._segments():
-            if isinstance(seg, ShardedSegment):
-                flat_keys.append(seg.keys.reshape(-1, seg.keys.shape[-1]))
-                flat = jax.tree.map(
-                    lambda a: a.reshape((-1,) + a.shape[2:]), seg.corpus)
-            else:
-                flat_keys.append(seg.keys)
-                flat = seg.corpus
-            w = seg.slots
-            dst = np.flatnonzero((idx >= off) & (idx < off + w))
-            srcs.append(flat)
-            src_idxs.append(idx[dst] - off)
-            dst_idxs.append(dst)
-            off += w
-        keys = jnp.concatenate(flat_keys, axis=0)[jnp.asarray(idx)]
+        one-program path — they are a few bytes per item. The row order and
+        the keys are one ``lsh.fold.order`` span."""
+        with tracing.span("lsh.fold.order"):
+            idx = self._live_slots_seq_order()
+            flat_keys = []
+            srcs, src_idxs, dst_idxs = [], [], []
+            off = 0
+            for seg in self._segments():
+                if isinstance(seg, ShardedSegment):
+                    flat_keys.append(seg.keys.reshape(-1, seg.keys.shape[-1]))
+                    flat = jax.tree.map(
+                        lambda a: a.reshape((-1,) + a.shape[2:]), seg.corpus)
+                else:
+                    flat_keys.append(seg.keys)
+                    flat = seg.corpus
+                w = seg.slots
+                dst = np.flatnonzero((idx >= off) & (idx < off + w))
+                srcs.append(flat)
+                src_idxs.append(idx[dst] - off)
+                dst_idxs.append(dst)
+                off += w
+            keys = jnp.concatenate(flat_keys, axis=0)[jnp.asarray(idx)]
         corpus = gather_rows_chunked(srcs[0], srcs, src_idxs, dst_idxs,
                                      idx.size, chunk=chunk)
         return keys, corpus

@@ -70,6 +70,7 @@ from typing import Any
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.core.index import ShardedLSHIndex
 from repro.core.segments import SegmentStore, ShardedSegment, TableSegment
 from repro.serving.lsh_service import LSHService
@@ -448,8 +449,16 @@ class MutationLog:
             pass
         self._off = start
 
-    def _append_sync(self, kind: str, tree) -> tuple[int, int, int]:
-        """Committer-thread body: -> (lsn, record offset, aligned size)."""
+    def _append_sync(self, kind: str, tree,
+                     seq: int | None = None) -> tuple[int, int, int]:
+        """Committer-thread body: -> (lsn, record offset, aligned size).
+        One ``lsh.wal.append`` span carrying the caller's ``seq``, with a
+        ``lsh.wal.sync`` child around the ``fdatasync``."""
+        with tracing.span("lsh.wal.append", seq=seq, lsn=self.next_lsn,
+                          kind=kind):
+            return self._append_record(kind, tree)
+
+    def _append_record(self, kind: str, tree) -> tuple[int, int, int]:
         frame, blobs = _encode_record(self.next_lsn, kind, tree)
         need = _aligned(len(frame) + sum(b.nbytes for b in blobs))
         self._max_record = max(self._max_record, need)
@@ -478,7 +487,8 @@ class MutationLog:
                 for b in blobs:
                     if b.nbytes:
                         os.write(self._fd, b.reshape(-1).view(np.uint8).data)
-            os.fdatasync(self._fd)
+            with tracing.span("lsh.wal.sync"):
+                os.fdatasync(self._fd)
         except BaseException:
             self._wind_back(start, need)
             raise
@@ -493,9 +503,11 @@ class MutationLog:
         """Start committing one record. Raises before touching the file
         on an armed ``pre_wal_append`` fault (the record is *not*
         committed); otherwise the write + sync proceed on the committer
-        thread while the caller applies the mutation in memory."""
+        thread while the caller applies the mutation in memory. The
+        committer's spans carry the caller's ``tracing.current_seq()``."""
         self.injector.fire("pre_wal_append")
-        return self._committer.submit(self._append_sync, kind, tree)
+        return self._committer.submit(self._append_sync, kind, tree,
+                                      tracing.current_seq())
 
     def finish(self, token: Future) -> int:
         """Join a ``begin``; -> the record's lsn, now durable. An armed
@@ -764,9 +776,9 @@ class DurableLSHService(LSHService):
     # -- write-ahead commit --------------------------------------------------
 
     def _commit(self, kind: str, tree) -> int:
-        t0 = time.perf_counter()
-        lsn = self._log.append(kind, tree)
-        self.stats.wal_ms += (time.perf_counter() - t0) * 1e3
+        with tracing.span("lsh.wal.finish") as wait:
+            lsn = self._log.append(kind, tree)
+        self.stats.wal_ms += wait.seconds * 1e3
         self.stats.wal_appends += 1
         return lsn
 
@@ -778,25 +790,27 @@ class DurableLSHService(LSHService):
         latencies serially. An apply failure cancels the record (it must
         not replay); a commit failure after a successful apply leaves
         memory ahead of the log, so the service degrades rather than
-        commit further ops on top of unlogged state."""
-        t0 = time.perf_counter()
-        token = self._log.begin(kind, tree)
-        t_begin = time.perf_counter()
+        commit further ops on top of unlogged state.
+
+        ``wal_ms`` takes the caller's two waits on the log: the hand-off
+        (``lsh.wal.begin``) and the wait beyond the apply
+        (``lsh.wal.finish``)."""
+        with tracing.span("lsh.wal.begin") as begin:
+            token = self._log.begin(kind, tree)
         try:
             apply_fn()
         except BaseException:
             self._log.cancel(token)
             raise
-        t_apply = time.perf_counter()
         try:
-            self._log.finish(token)
+            with tracing.span("lsh.wal.finish") as wait:
+                self._log.finish(token)
         except InjectedCrash:
             raise               # durable AND applied: consistent as it lies
         except BaseException:
             self.health = "degraded"
             raise
-        self.stats.wal_ms += ((t_begin - t0)
-                              + (time.perf_counter() - t_apply)) * 1e3
+        self.stats.wal_ms += (begin.seconds + wait.seconds) * 1e3
         self.stats.wal_appends += 1
 
     def _maybe_snapshot(self) -> None:

@@ -56,6 +56,7 @@ from typing import Any, Sequence
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.core.index import (DeviceLSHIndex, HostLSHIndex, ShardedLSHIndex,
                               _SegmentedIndex)
 from repro.core.lsh import LSHFamily, make_family
@@ -229,6 +230,10 @@ class LSHService:
         the micro-batch scheduler pads coalesced batches to stable program
         shapes and passes the real request count so pad rows never inflate
         per-tenant stats.
+
+        The call is one ``lsh.query.call`` span (dispatch, wait, copy of
+        the answers to the host; ``total_ms`` is its duration) with a
+        ``lsh.query.wait`` child around the wait for the device.
         """
         probes = self.probes if probes is None else int(probes)
         if probes < 1:
@@ -252,18 +257,17 @@ class LSHService:
         n = jax.tree.leaves(queries)[0].shape[0]
         if stat_rows is not None:
             n = min(n, int(stat_rows))
-        t0 = time.perf_counter()
-        ids, scores, n_cand = jax.block_until_ready(
-            self.index.query_batch(queries, topk=topk, probes=probes,
-                                   mode=mode, rng=rng))
-        ids, scores, n_cand = (np.asarray(ids), np.asarray(scores),
-                               np.asarray(n_cand))
-        dt = (time.perf_counter() - t0) * 1e3
+        with tracing.span("lsh.query.call", n=n) as call:
+            out = self.index.query_batch(queries, topk=topk, probes=probes,
+                                         mode=mode, rng=rng)
+            with tracing.span("lsh.query.wait"):
+                jax.block_until_ready(out)
+            ids, scores, n_cand = (np.asarray(a) for a in out)
         self.stats.queries += n
         setattr(self.stats, f"{mode}_queries",
                 getattr(self.stats, f"{mode}_queries") + n)
         self.stats.batches += 1
-        self.stats.total_ms += dt
+        self.stats.total_ms += call.seconds * 1e3
         self.stats.total_candidates += int(n_cand.sum())
         return ids, scores, n_cand
 
@@ -309,17 +313,18 @@ class LSHService:
         slab on the sharded index — served immediately). A max_deltas
         auto-compaction triggered here is timed into ``auto_compact_ms``,
         never ``insert_ms`` — ``insert_items_per_s`` measures ingest, not
-        fold cost."""
+        fold cost. The apply is one ``lsh.index.insert`` span (its
+        duration less the fold's feeds ``insert_ms``)."""
         index = self._mutable_index()
         n = jax.tree.leaves(batch)[0].shape[0]
         auto_s0 = index.auto_compact_s
-        t0 = time.perf_counter()
-        index.insert(batch, batch_size=batch_size)
-        jax.block_until_ready(
-            [seg.sorted_keys for seg in
-             [index.store.base] + index.store.deltas])
-        dt_ms = (time.perf_counter() - t0) * 1e3
-        self.stats.insert_ms += dt_ms - (index.auto_compact_s - auto_s0) * 1e3
+        with tracing.span("lsh.index.insert", n=n) as apply:
+            index.insert(batch, batch_size=batch_size)
+            jax.block_until_ready(
+                [seg.sorted_keys for seg in
+                 [index.store.base] + index.store.deltas])
+        self.stats.insert_ms += (apply.seconds
+                                 - (index.auto_compact_s - auto_s0)) * 1e3
         self.stats.inserted += n
         self.stats.insert_batches += 1
         self._sync_mutation_stats()

@@ -55,6 +55,7 @@ sat queued too long with a ``RequestTimeout``.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import queue as queue_lib
 import threading
 import time
@@ -65,6 +66,7 @@ from typing import Any, Callable
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.core import segments
 from repro.serving.durability import (InjectedCrash, ServiceUnavailable,
                                       TransientIOError)
@@ -153,6 +155,7 @@ class _QueryReq:
 @dataclasses.dataclass
 class _IngestReq:
     ns: _Namespace
+    kind: str                  # insert, delete, compact, rebalance, recover
     fn: Callable
     future: Future
     t_submit: float
@@ -210,6 +213,7 @@ class ServingScheduler:
         self._query_q: queue_lib.Queue = queue_lib.Queue()
         self._ingest_q: queue_lib.Queue = queue_lib.Queue()
         self._queries_inflight = 0   # submitted, future not yet resolved
+        self._ingest_seq = itertools.count()  # numbers the ingest lane's ops
         self._closed = False
         self._query_thread = threading.Thread(
             target=self._query_loop, name="lsh-query-lane", daemon=True)
@@ -308,7 +312,7 @@ class ServingScheduler:
                 f"namespace {ns.name!r} serves a non-durable service; "
                 "recovery needs a DurableLSHService")
         self._admit(ns)
-        return self._submit_ingest(ns, recover)
+        return self._submit_ingest(ns, "recover", recover)
 
     # -- submission API -----------------------------------------------------
 
@@ -347,7 +351,8 @@ class ServingScheduler:
         self._shed_unless_serving(ns)
         n = jax.tree.leaves(batch)[0].shape[0]
         self._admit(ns, new_items=n)
-        return self._submit_ingest(ns, lambda: ns.service.insert(batch))
+        return self._submit_ingest(ns, "insert",
+                                   lambda: ns.service.insert(batch))
 
     def delete(self, ids, *, tenant: str = "default") -> Future:
         """Submit a delete to the ingest lane; resolves to the count."""
@@ -355,7 +360,8 @@ class ServingScheduler:
         self._check_open()
         self._shed_unless_serving(ns)
         self._admit(ns)
-        return self._submit_ingest(ns, lambda: ns.service.delete(ids))
+        return self._submit_ingest(ns, "delete",
+                                   lambda: ns.service.delete(ids))
 
     def compact(self, tenant: str = "default") -> Future:
         """Queue a compaction on the ingest lane: the replacement store is
@@ -366,7 +372,8 @@ class ServingScheduler:
         self._shed_unless_serving(ns)
         self._admit(ns)
         return self._submit_ingest(
-            ns, lambda: ns.service.apply_swap(ns.service.prepare_compact()))
+            ns, "compact",
+            lambda: ns.service.apply_swap(ns.service.prepare_compact()))
 
     def rebalance(self, tenant: str = "default") -> Future:
         """Queue a rebalance (sharded tenants) — same prepare/flip split."""
@@ -375,11 +382,11 @@ class ServingScheduler:
         self._shed_unless_serving(ns)
         self._admit(ns)
         return self._submit_ingest(
-            ns,
+            ns, "rebalance",
             lambda: ns.service.apply_swap(ns.service.prepare_rebalance()))
 
-    def _submit_ingest(self, ns: _Namespace, fn) -> Future:
-        req = _IngestReq(ns=ns, fn=fn, future=Future(),
+    def _submit_ingest(self, ns: _Namespace, kind: str, fn) -> Future:
+        req = _IngestReq(ns=ns, kind=kind, fn=fn, future=Future(),
                          t_submit=time.perf_counter())
         self._ingest_q.put(req)
         return self._done(ns, req.future)
@@ -489,38 +496,41 @@ class ServingScheduler:
             f"{self.timeout_s * 1e3:g} ms (request_timeout_ms)"))
 
     def _run_group(self, reqs: list[_QueryReq]) -> None:
-        if self.timeout_s is not None:
-            now, live = time.perf_counter(), []
-            for req in reqs:
-                if now - req.t_submit > self.timeout_s:
-                    self._expire(req)
-                else:
-                    live.append(req)
-            reqs = live
-            if not reqs:
-                return
-        head = reqs[0]
-        try:
-            b = len(reqs)
-            padded = 1 << (b - 1).bit_length()  # stable program shapes
-            stacked = jax.tree.map(
-                lambda *xs: np.stack([np.asarray(x) for x in xs]),
-                *[r.x for r in reqs])
-            if padded > b:
+        """One coalesced group -> one service call: a ``lsh.sched.batch``
+        span (``n`` requests)."""
+        with tracing.span("lsh.sched.batch", n=len(reqs)):
+            if self.timeout_s is not None:
+                now, live = time.perf_counter(), []
+                for req in reqs:
+                    if now - req.t_submit > self.timeout_s:
+                        self._expire(req)
+                    else:
+                        live.append(req)
+                reqs = live
+                if not reqs:
+                    return
+            head = reqs[0]
+            try:
+                b = len(reqs)
+                padded = 1 << (b - 1).bit_length()  # stable program shapes
                 stacked = jax.tree.map(
-                    lambda a: np.concatenate(
-                        [a, np.repeat(a[:1], padded - b, axis=0)]),
-                    stacked)
-            ids, scores, n_cand = head.ns.service.query_arrays(
-                stacked, topk=head.topk, probes=head.probes, mode=head.mode,
-                seed=head.seed, stat_rows=b)
-            for i, req in enumerate(reqs):
-                req.future.set_result(
-                    (ids[i], scores[i], int(n_cand[i])))
-        except BaseException as exc:  # resolve every waiter, never wedge
-            for req in reqs:
-                if not req.future.done():
-                    req.future.set_exception(exc)
+                    lambda *xs: np.stack([np.asarray(x) for x in xs]),
+                    *[r.x for r in reqs])
+                if padded > b:
+                    stacked = jax.tree.map(
+                        lambda a: np.concatenate(
+                            [a, np.repeat(a[:1], padded - b, axis=0)]),
+                        stacked)
+                ids, scores, n_cand = head.ns.service.query_arrays(
+                    stacked, topk=head.topk, probes=head.probes,
+                    mode=head.mode, seed=head.seed, stat_rows=b)
+                for i, req in enumerate(reqs):
+                    req.future.set_result(
+                        (ids[i], scores[i], int(n_cand[i])))
+            except BaseException as exc:  # resolve every waiter, never wedge
+                for req in reqs:
+                    if not req.future.done():
+                        req.future.set_exception(exc)
 
     def _ingest_loop(self) -> None:
         while True:
@@ -533,42 +543,49 @@ class ServingScheduler:
             self._run_ingest(item)
 
     def _run_ingest(self, req: _IngestReq) -> None:
+        """One ingest op, retries included: a ``lsh.ingest.<kind>`` span
+        whose ``seq`` numbers the lane's ops; the WAL and fold spans the op
+        opens carry the same ``seq``."""
         if (self.timeout_s is not None
                 and time.perf_counter() - req.t_submit > self.timeout_s):
             self._expire(req)
             return
-        attempt = 0
-        while True:
-            try:
-                # mutations on this lane run cooperatively: the throttled
-                # store-build loops yield the core between bounded
-                # programs — but only while a query is actually in flight
-                # — so a pending query-lane batch submits ahead of the
-                # next build chunk and runs with most of the core instead
-                # of convoying behind the whole build (decisive on
-                # few-core hosts, where the lane thread otherwise keeps
-                # the CPU after every block)
-                with segments.cooperative_build(busy=self._queries_waiting):
-                    req.future.set_result(req.fn())
-                return
-            except TransientIOError as exc:
-                # retryable IO on the durability plane: nothing was
-                # committed, so re-running the mutation is safe
-                if attempt >= self.ingest_retries:
+        with tracing.span(f"lsh.ingest.{req.kind}",
+                          seq=next(self._ingest_seq)):
+            attempt = 0
+            while True:
+                try:
+                    # mutations on this lane run cooperatively: the
+                    # throttled store-build loops yield the core between
+                    # bounded programs — but only while a query is actually
+                    # in flight — so a pending query-lane batch submits
+                    # ahead of the next build chunk and runs with most of
+                    # the core instead of convoying behind the whole build
+                    # (decisive on few-core hosts, where the lane thread
+                    # otherwise keeps the CPU after every block)
+                    with segments.cooperative_build(
+                            busy=self._queries_waiting):
+                        req.future.set_result(req.fn())
+                    return
+                except TransientIOError as exc:
+                    # retryable IO on the durability plane: nothing was
+                    # committed, so re-running the mutation is safe
+                    if attempt >= self.ingest_retries:
+                        self._record_error(req.ns, exc)
+                        self._set_health(req.ns, "degraded")
+                        req.future.set_exception(exc)
+                        return
+                    attempt += 1
+                    self.stats.retries += 1
+                    req.ns.service.stats.retries += 1
+                    time.sleep(min(self.backoff_s * 2 ** (attempt - 1), 1.0))
+                except BaseException as exc:
+                    # non-retryable: record it on the tenant so a dropped
+                    # future can't swallow a failed mutation; a simulated
+                    # crash leaves memory state untrusted -> degrade
                     self._record_error(req.ns, exc)
-                    self._set_health(req.ns, "degraded")
+                    if isinstance(exc, InjectedCrash):
+                        self._set_health(req.ns, "degraded")
                     req.future.set_exception(exc)
                     return
-                attempt += 1
-                self.stats.retries += 1
-                req.ns.service.stats.retries += 1
-                time.sleep(min(self.backoff_s * 2 ** (attempt - 1), 1.0))
-            except BaseException as exc:
-                # non-retryable: record it on the tenant so a dropped
-                # future can't swallow a failed mutation; a simulated
-                # crash leaves memory state untrusted -> degrade
-                self._record_error(req.ns, exc)
-                if isinstance(exc, InjectedCrash):
-                    self._set_health(req.ns, "degraded")
-                req.future.set_exception(exc)
-                return
+
