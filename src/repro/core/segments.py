@@ -563,15 +563,33 @@ def _sort_tables_throttled(keys_t: jax.Array):
         return perm, sorted_keys, jnp.max(jnp.stack([o[2] for o in outs]))
 
 
+def _row_flat(shape: tuple[int, ...]) -> tuple[int, ...]:
+    """The shape the chunked copy writes a leaf of ``shape`` in: (rows,
+    prod(rest)) for ndim > 2, else ``shape`` itself."""
+    if len(shape) <= 2:
+        return tuple(shape)
+    return (shape[0], int(np.prod(shape[1:])))
+
+
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _scatter_rows_chunk(buf, src, src_idx, dst_idx):
     """One bounded program of the chunked shadow-build copy: gather
-    ``src_idx`` rows from one source segment and scatter them into the
-    donated destination buffers in place (``dst_idx`` past the end marks
-    chunk padding and is dropped). Donation makes the update O(chunk), not
-    O(buffer): the runtime aliases the output onto the input allocation."""
+    ``src_idx`` rows from one source segment in its own layout, flatten
+    them to the destination's row width, and scatter them into the donated
+    row-flat destination buffers in place (``dst_idx`` past the end marks
+    chunk padding and is dropped).
+
+    The destination is row-flat (``_row_flat``) because a TPU keeps the row
+    axis of an (n, 8, 16) buffer minor, while the scatter writes whole rows:
+    against a 3-D destination the compiler copies the whole donated buffer
+    into the scatter's layout and back in every chunk, so each chunk costs
+    O(buffer). An (n, 128) destination is scattered in place, and donation
+    keeps the update O(chunk): the runtime aliases the output onto the
+    input allocation."""
     return jax.tree.map(
-        lambda b, s: b.at[dst_idx].set(s[src_idx], mode="drop"), buf, src)
+        lambda b, s: b.at[dst_idx].set(
+            s[src_idx].reshape(src_idx.shape + b.shape[1:]), mode="drop"),
+        buf, src)
 
 
 def gather_rows_chunked(template, srcs, src_idxs, dst_idxs, out_rows, *,
@@ -594,14 +612,21 @@ def gather_rows_chunked(template, srcs, src_idxs, dst_idxs, out_rows, *,
     unwritten rows stay zero, matching the pad-row zeros of the
     one-program path.
 
+    The chunks write row-flat buffers (``_scatter_rows_chunk`` says why);
+    after the last chunk one program per leaf of ndim > 2 re-shapes its
+    buffer to ``(out_rows,) + leaf.shape[1:]``, one copy of the leaf.
+
     ``srcs`` are per-segment corpus pytrees with a flat leading axis;
     ``src_idxs``/``dst_idxs`` the matching host-side row maps into them
     and into the flat output. ``template`` supplies output leaf shapes.
-    The whole copy is one ``lsh.fold.gather`` span.
+    The whole copy is one ``lsh.fold.gather`` span; ``chunks=`` counts its
+    chunk programs.
     """
-    with tracing.span("lsh.fold.gather", rows=int(out_rows)):
+    chunks = sum(-(-len(s_idx) // chunk) for s_idx in src_idxs)
+    with tracing.span("lsh.fold.gather", rows=int(out_rows), chunks=chunks):
         buf = jax.tree.map(
-            lambda a: jnp.zeros((out_rows,) + a.shape[1:], a.dtype), template)
+            lambda a: jnp.zeros(_row_flat((out_rows,) + a.shape[1:]),
+                                a.dtype), template)
         for src, s_idx, d_idx in zip(srcs, src_idxs, dst_idxs):
             for c0 in range(0, len(s_idx), chunk):
                 s_c = np.asarray(s_idx[c0:c0 + chunk], np.int32)
@@ -614,7 +639,10 @@ def gather_rows_chunked(template, srcs, src_idxs, dst_idxs, out_rows, *,
                                           jnp.asarray(d_c))
                 jax.block_until_ready(jax.tree.leaves(buf))
                 _yield_slot()
-        return buf
+        out = jax.tree.map(
+            lambda b, a: b.reshape((out_rows,) + a.shape[1:]), buf, template)
+        jax.block_until_ready(jax.tree.leaves(out))
+        return out
 
 
 # ---------------------------------------------------------------------------

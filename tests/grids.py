@@ -15,7 +15,7 @@ just ``import grids``.
 import jax
 import pytest
 
-from repro.core import make_family
+from repro.core import CPTensor, make_family
 from repro.core.lsh import ALL_KINDS, E2LSH_KINDS, SRP_KINDS  # noqa: F401
                                       # (re-exported: the grid axes)
 
@@ -75,6 +75,20 @@ def corpus_and_queries(n_corpus: int, n_queries: int, dims=DIMS,
     queries = corpus[:n_queries] + noise * jax.random.normal(
         kq, (n_queries,) + dims)
     return corpus, queries
+
+
+def cp_corpus_and_queries(n_corpus: int, n_queries: int, dims=DIMS,
+                          rank: int = 3, seed: int = 0, noise: float = 0.1):
+    """The CP-format twin of ``corpus_and_queries``: rank-``rank``
+    ``CPTensor`` items, each factor a (n, d, rank) leaf, with queries
+    perturbed off the first items' factors."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2 * len(dims))
+    factors = tuple(jax.random.normal(k, (n_corpus, d, rank)) / d ** 0.5
+                    for k, d in zip(keys, dims))
+    queries = tuple(
+        f[:n_queries] + noise * jax.random.normal(k, f[:n_queries].shape)
+        for f, k in zip(factors, keys[len(dims):]))
+    return CPTensor(factors=factors), CPTensor(factors=queries)
 
 
 def assert_query_path(index) -> None:

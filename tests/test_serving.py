@@ -20,10 +20,13 @@ S in {1, 2, 4}):
 
 import threading
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import grids
+from repro.core import segments
 from repro.serving.lsh_service import LSHService
 from repro.serving.scheduler import (QuotaExceeded, ServingScheduler,
                                      TenantQuota)
@@ -36,8 +39,10 @@ N_INS = 13
 LAYOUTS = (None,) + grids.SHARD_COUNTS    # device + sharded S in {1,2,4}
 
 
-def _service(shards, **kw):
-    corpus, queries = grids.corpus_and_queries(N_CORPUS, N_QUERIES)
+def _service(shards, fmt="dense", **kw):
+    make = (grids.cp_corpus_and_queries if fmt == "cp"
+            else grids.corpus_and_queries)
+    corpus, queries = make(N_CORPUS, N_QUERIES)
     kw.setdefault("bucket_cap", 16)
     kw.setdefault("max_deltas", 64)       # no auto-compact under the races
     svc = LSHService(grids.grid_family("cp-e2lsh"), metric="euclidean",
@@ -48,7 +53,7 @@ def _service(shards, **kw):
 def _mutate(svc, corpus):
     """One delta slab + tombstones in both base and delta, so the fold
     has real compaction work (not a no-op flip)."""
-    svc.insert(np.asarray(corpus[:N_INS]) + 0.5)
+    svc.insert(jax.tree.map(lambda a: np.asarray(a[:N_INS]) + 0.5, corpus))
     svc.delete([3, 10, 25, N_CORPUS + 2])
 
 
@@ -152,10 +157,12 @@ class TestChunkedFoldParity:
     implementation detail: its store must be bit-identical to the
     monolithic fold's, down to every segment array."""
 
-    @pytest.mark.parametrize("shards", LAYOUTS)
-    def test_chunked_store_bit_identical_to_monolithic(self, shards):
-        svc_mono, corpus, queries = _service(shards)
-        svc_chunk, _, _ = _service(shards)
+    @pytest.mark.parametrize("shards,fmt", [
+        *(pytest.param(s, "dense", id=str(s)) for s in LAYOUTS),
+        *(pytest.param(s, "cp", id=f"cp-{s}") for s in LAYOUTS)])
+    def test_chunked_store_bit_identical_to_monolithic(self, shards, fmt):
+        svc_mono, corpus, queries = _service(shards, fmt)
+        svc_chunk, _, _ = _service(shards, fmt)
         svc_mono.index.swap_chunk_rows = None
         svc_chunk.index.swap_chunk_rows = 16   # many chunks over 67 items
         for svc in (svc_mono, svc_chunk):
@@ -167,10 +174,46 @@ class TestChunkedFoldParity:
         np.testing.assert_array_equal(np.asarray(a.sorted_keys),
                                       np.asarray(b.sorted_keys))
         np.testing.assert_array_equal(np.asarray(a.perm), np.asarray(b.perm))
-        import jax
         for la, lb in zip(jax.tree.leaves(a.corpus), jax.tree.leaves(b.corpus)):
             np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
         _assert_same(_answers(svc_mono, queries), _answers(svc_chunk, queries))
+
+
+class TestGatherRowsChunked:
+    """``gather_rows_chunked`` against a plain NumPy gather, over leaves of
+    ndim 1 to 4: ids, keys, dense (m, 8, 16) rows, CP factors and TT
+    cores, which the chunks write row-flat and re-shape at the end."""
+
+    LEAVES = {"ids": ((), np.int32), "keys": ((3,), np.uint32),
+              "dense": ((8, 16), np.float32), "cp": ((4, 2), np.float32),
+              "tt": ((1, 4, 2), np.float32)}
+    OUT_ROWS = 40
+
+    @pytest.mark.parametrize("chunk", [4, 7, 64])
+    def test_matches_numpy_gather(self, chunk):
+        rng = np.random.default_rng(chunk)
+        srcs = [{k: (rng.standard_normal((m,) + shape) * 100).astype(dt)
+                 for k, (shape, dt) in self.LEAVES.items()}
+                for m in (23, 9)]
+        # 25 of 40 output rows are written, from both sources; the rest
+        # must stay zero
+        dst = rng.permutation(self.OUT_ROWS)[:25]
+        dst_idxs = [dst[:17], dst[17:]]
+        src_idxs = [rng.choice(23, 17, replace=False),
+                    rng.choice(9, 8, replace=False)]
+        out = segments.gather_rows_chunked(
+            jax.tree.map(jnp.asarray, srcs[0]),
+            [jax.tree.map(jnp.asarray, src) for src in srcs],
+            src_idxs, dst_idxs, self.OUT_ROWS, chunk=chunk)
+        for k, (shape, dt) in self.LEAVES.items():
+            want = np.zeros((self.OUT_ROWS,) + shape, dt)
+            for src, s_idx, d_idx in zip(srcs, src_idxs, dst_idxs):
+                want[d_idx] = src[k][s_idx]
+            got = np.asarray(out[k])
+            assert got.dtype == want.dtype and got.shape == want.shape, k
+            np.testing.assert_array_equal(got, want, err_msg=k)
+            unwritten = np.setdiff1d(np.arange(self.OUT_ROWS), dst)
+            assert not got[unwritten].any(), k
 
 
 class TestSchedulerCoalescing:

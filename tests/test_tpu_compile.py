@@ -14,6 +14,7 @@ while a module is imported (see the fixture's docstring).
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -177,6 +178,45 @@ def test_in_format_query_fits_one_chip(one_chip, kind, n):
         metric="euclidean" if kind == "cp-e2lsh" else "cosine", topk=10,
         caps=(CAP,)).compile()
     _fits(compiled)
+
+
+# an HLO instruction with an array result: its name, leading dim, opcode
+_HLO_ARRAY_OP = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = \w+\[(\d+)[,\]]\S* ([\w\-]+)\(",
+    re.M)
+
+
+def _copies_of_rows(hlo_text: str, rows: int) -> list[str]:
+    """Names of the copies (``copy`` ops and copy fusions) in a compiled
+    module whose result has ``rows`` rows."""
+    return [m.group(1) for m in _HLO_ARRAY_OP.finditer(hlo_text)
+            if int(m.group(2)) == rows
+            and (m.group(3) == "copy" or "copy" in m.group(1))]
+
+
+@pytest.mark.parametrize("leaf", [MODES, (12, RANK), (1, 12, RANK)],
+                         ids=["dense", "cp-factor", "tt-first-core"])
+def test_fold_chunk_program_copies_no_whole_buffer(one_chip, leaf):
+    """The fold's chunk program at chunk 4096 over N rows (dense (N, 8,
+    16) rows, a CP factor (N, 12, 4), a TT first core (N, 1, 12, 4))
+    scatters into its row-flat destination in place: no copy in it
+    produces an N-row buffer. The same program with a 3-D (N, 8, 16)
+    destination, as the fold wrote before its buffers were row-flat, holds
+    two (``copy`` and ``copy.2``): the donated buffer moved into the
+    scatter's layout and back, in every chunk."""
+    chunk = 4096
+    shape = (N,) + leaf
+    idx = _spec((chunk,), jnp.int32, one_chip)
+
+    def copies(dst_shape):
+        compiled = segments._scatter_rows_chunk.lower(
+            _spec(dst_shape, jnp.float32, one_chip),
+            _spec(shape, jnp.float32, one_chip), idx, idx).compile()
+        return _copies_of_rows(compiled.as_text(), N)
+
+    assert copies(segments._row_flat(shape)) == []
+    if leaf == MODES:
+        assert len(copies(shape)) == 2
 
 
 def test_shard_map_query_quarter_bytes_per_chip(topo, one_chip):
